@@ -21,6 +21,7 @@ Two modes:
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import queue
 import threading
@@ -57,7 +58,15 @@ class StreamingServer:
     the server will never finish.  If the engine raises on the loop
     thread, the loop records the exception and unblocks every open
     stream at once; ``stream()``, ``submit()`` and ``shutdown()`` then
-    re-raise it."""
+    re-raise it.
+
+    For a profiler trace (``jax.profiler.start_trace`` around a live
+    server) the loop thread writes ``server.inbox`` and ``server.idle``
+    spans around the engine's own, and each garbage collection in the
+    process is a ``gc`` span from ``start()`` to ``shutdown()``
+    (docs/STREAMING.md §5).  ``submit`` stamps ``arrival_us``, so a
+    request's queue wait counts from its hand-over, not from the tick
+    that drains the inbox."""
 
     def __init__(self, engine: ServingEngine, *, idle_s: float = 0.001):
         self.engine = engine
@@ -70,6 +79,7 @@ class StreamingServer:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._error: Optional[Exception] = None
+        self._gc_span: Optional[jax.profiler.TraceAnnotation] = None
 
     @property
     def running(self) -> bool:
@@ -82,6 +92,7 @@ class StreamingServer:
         if self.running:
             raise RuntimeError("server already running")
         self._stop.clear()
+        gc.callbacks.append(self._on_gc)
         self._thread = threading.Thread(target=self._loop,
                                         name="serving-loop", daemon=True)
         self._thread.start()
@@ -103,6 +114,7 @@ class StreamingServer:
             if uid in self._streams:
                 raise ValueError(f"uid {uid} already submitted")
             self._streams[uid] = queue.Queue()
+            req_kw.setdefault("arrival_us", self.engine.clock())
             self._inbox.append(Request(
                 uid=uid, tokens=np.asarray(tokens, np.int32),
                 max_new_tokens=max_new_tokens, **req_kw))
@@ -140,6 +152,8 @@ class StreamingServer:
         if self._thread is not None:
             self._thread.join(timeout=timeout)
             self._thread = None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
         if self._error is not None:
             raise self._error
         self.engine.drain()
@@ -158,16 +172,29 @@ class StreamingServer:
     def _on_token(self, ev: StreamEvent) -> None:
         self._streams[ev.uid].put(ev)
 
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """A ``gc`` span from each collection's start to its stop (a
+        collection runs start to stop on one thread, never nested)."""
+        if phase == "start":
+            self._gc_span = jax.profiler.TraceAnnotation(
+                "gc", generation=info["generation"])
+            self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            self._gc_span.__exit__(None, None, None)
+            self._gc_span = None
+
     def _loop(self) -> None:
         try:
             while not self._stop.is_set():
-                with self._lock:
-                    pending, self._inbox = self._inbox, []
-                for req in pending:
-                    self.engine.submit(req)
+                with jax.profiler.TraceAnnotation("server.inbox"):
+                    with self._lock:
+                        pending, self._inbox = self._inbox, []
+                    for req in pending:
+                        self.engine.submit(req)
                 if not self.engine.step():
                     # idle: engine fully drained — nap until new work
-                    self._stop.wait(self._idle_s)
+                    with jax.profiler.TraceAnnotation("server.idle"):
+                        self._stop.wait(self._idle_s)
             self.engine.drain()
         except Exception as e:   # surfaced by stream()/submit()/shutdown()
             self._error = e

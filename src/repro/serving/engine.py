@@ -177,7 +177,9 @@ STREAMING_FAMILIES = ("dense", "moe", "ssm", "hybrid", "vlm")
 def default_clock() -> int:
     """Host time in µs — the clock policies age/deadline against.
     Engines and hosts accept a ``clock`` override so the arrival
-    benchmark can drive the same scheduling code on virtual time."""
+    benchmark can drive the same scheduling code on virtual time.
+    It is ``time.monotonic()``'s clock in whole µs, so a stamp taken
+    here and a ``time.monotonic()`` reading subtract directly."""
     return time.monotonic_ns() // 1000
 
 
@@ -204,8 +206,19 @@ class RequestResult:
     """Accumulated outcome of a Request: emitted tokens and timings.
     ``preemptions`` counts how many times the request was evicted from
     a slot and later resumed (0 = ran uninterrupted).
-    ``first_token_us`` is the engine-clock stamp of the first emitted
-    token — ``first_token_us - arrival_us`` is the request's TTFT."""
+
+    Engine-clock stamps: ``arrival_us`` is the request's (copied from
+    ``Request.arrival_us``), ``admitted_us`` is when it first took a
+    slot (a restore after preemption keeps the first), and
+    ``first_token_us`` is when its first token was emitted.  So
+    ``admitted_us - arrival_us`` is its queue wait,
+    ``first_token_us - admitted_us`` its service time to the first
+    token, and ``first_token_us - arrival_us`` its TTFT.
+
+    ``prefill_s`` and ``decode_s`` are host seconds: dispatching the
+    prefill and decode calls, plus, under ``overlap``, the wait on each
+    step's tokens.  JAX dispatches asynchronously, so they are host
+    dispatch and wait time, not device time."""
 
     uid: int
     prompt_len: int
@@ -215,6 +228,8 @@ class RequestResult:
     done: bool = False
     preemptions: int = 0
     first_token_us: Optional[int] = None
+    arrival_us: Optional[int] = None
+    admitted_us: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -516,10 +531,13 @@ class ServingEngine:
         self._chunking: Dict[int, _ChunkState] = {}
         self._ckpt: Dict[int, SlotCheckpoint] = {}
         # what the last step() did — the benchmark's virtual-clock cost
-        # hook: prefill token counts, chunk dispatches, decode dispatch
+        # hook: prefill token counts, chunk dispatches, decode dispatch;
+        # and where its wall time went: ``readback_s`` blocked on the
+        # device's tokens, ``host_s`` the rest of the tick
         self.last_step: Dict[str, Any] = {"prefill_tokens": [],
                                           "chunks": 0, "decoded": False,
-                                          "processed": 0}
+                                          "processed": 0,
+                                          "readback_s": 0.0, "host_s": 0.0}
 
         # --- compiled steps (init-time, like interpreter prepare) -----
         # Resolve prefill/decode through the op registry tag chain: the
@@ -702,7 +720,8 @@ class ServingEngine:
             req.arrival_us = self.clock()
         self.queue.append(req)
         self.results[req.uid] = RequestResult(uid=req.uid,
-                                              prompt_len=len(req.tokens))
+                                              prompt_len=len(req.tokens),
+                                              arrival_us=req.arrival_us)
 
     def insert_slot_state(self, slot: int, new_cache: Any) -> None:
         """Place a prefilled (batch=1) state pytree into slot ``slot``
@@ -887,25 +906,28 @@ class ServingEngine:
         n = len(req.tokens)
         if n >= 2:
             m = n - 1
-            prompt = np.asarray(req.tokens[:-1])
-            if self.bucket_table is not None:
-                prompt = self._padded_prompt(prompt)
-            batch = {"tokens": jnp.asarray(prompt[None])}
-            if self._moe_masked:
-                # capacity-stable bucketed-MoE scalars: capacity is a
-                # function of the BUCKET shape inside the trace, while
-                # these traced values mask dispatch to exactly what the
-                # true length m routes (lm.moe_dispatch).  Emitted even
-                # on the over-cap exact-length fallback (where they
-                # degenerate to unmasked semantics) so every prefill of
-                # a given shape shares one trace signature.
-                from repro.models.lm import moe_capacity
-                batch["n_valid"] = jnp.int32(m)
-                batch["moe_cap"] = jnp.int32(moe_capacity(self.cfg, m))
-            if req.extras:
-                for k, v in req.extras.items():
-                    batch[k] = jnp.asarray(v[None])
-            _, cache1 = self._prefill((self.params, batch))
+            with jax.profiler.TraceAnnotation("engine.prefill",
+                                              uid=req.uid, tokens=m):
+                prompt = np.asarray(req.tokens[:-1])
+                if self.bucket_table is not None:
+                    prompt = self._padded_prompt(prompt)
+                batch = {"tokens": jnp.asarray(prompt[None])}
+                if self._moe_masked:
+                    # capacity-stable bucketed-MoE scalars: capacity is
+                    # a function of the BUCKET shape inside the trace,
+                    # while these traced values mask dispatch to exactly
+                    # what the true length m routes (lm.moe_dispatch).
+                    # Emitted even on the over-cap exact-length fallback
+                    # (where they degenerate to unmasked semantics) so
+                    # every prefill of a given shape shares one trace
+                    # signature.
+                    from repro.models.lm import moe_capacity
+                    batch["n_valid"] = jnp.int32(m)
+                    batch["moe_cap"] = jnp.int32(moe_capacity(self.cfg, m))
+                if req.extras:
+                    for k, v in req.extras.items():
+                        batch[k] = jnp.asarray(v[None])
+                _, cache1 = self._prefill((self.params, batch))
             self.last_step["prefill_tokens"].append(len(prompt))
             self.policy.charge(req.tenant, 1.0)
         else:   # single-token prompt: slot starts from a fresh cache
@@ -950,11 +972,13 @@ class ServingEngine:
             return
         t0 = time.perf_counter()
         first = np.asarray(req.tokens[:self.chunk_tokens])
-        batch = {"tokens": jnp.asarray(first[None])}
-        if req.extras:
-            for k, v in req.extras.items():
-                batch[k] = jnp.asarray(v[None])
-        _, cache1 = self._prefill((self.params, batch))
+        with jax.profiler.TraceAnnotation("engine.prefill", uid=req.uid,
+                                          tokens=len(first)):
+            batch = {"tokens": jnp.asarray(first[None])}
+            if req.extras:
+                for k, v in req.extras.items():
+                    batch[k] = jnp.asarray(v[None])
+            _, cache1 = self._prefill((self.params, batch))
         self.last_step["prefill_tokens"].append(len(first))
         self.policy.charge(req.tenant, 1.0)
         if self.paged:
@@ -983,29 +1007,32 @@ class ServingEngine:
         prompt = np.asarray(cs.req.tokens[:-1])
         tok = prompt[cs.done:cs.done + self.chunk_tokens]
         real = len(tok)
-        if real < self.chunk_tokens:
-            tok = np.concatenate(
-                [tok, np.zeros(self.chunk_tokens - real, tok.dtype)])
-        start = cs.done + self._vis()
-        if self.paged:
-            self._ensure_blocks(
-                slot, min(start + self.chunk_tokens - 1,
-                          self.cache_len - 1))
-            self.kv_pool = self._pin_kv(self._prefill_chunk(
-                (self.params, self.kv_pool, self._table_row(slot),
-                 jnp.asarray(tok[None]), jnp.int32(start))))
-        elif self._recurrent_chunk:
-            # carried-state dispatch: the chunk's true token count rides
-            # along as a traced scalar — the padded tail of the final
-            # chunk is an exact state no-op (dt masked to zero), so one
-            # compiled program serves full and partial chunks alike
-            cs.cache1 = self._pin_c1(self._prefill_chunk(
-                (self.params, cs.cache1, jnp.asarray(tok[None]),
-                 jnp.int32(start), jnp.int32(real))))
-        else:
-            cs.cache1 = self._pin_c1(self._prefill_chunk(
-                (self.params, cs.cache1, jnp.asarray(tok[None]),
-                 jnp.int32(start))))
+        with jax.profiler.TraceAnnotation("engine.prefill",
+                                          uid=cs.req.uid, tokens=real):
+            if real < self.chunk_tokens:
+                tok = np.concatenate(
+                    [tok, np.zeros(self.chunk_tokens - real, tok.dtype)])
+            start = cs.done + self._vis()
+            if self.paged:
+                self._ensure_blocks(
+                    slot, min(start + self.chunk_tokens - 1,
+                              self.cache_len - 1))
+                self.kv_pool = self._pin_kv(self._prefill_chunk(
+                    (self.params, self.kv_pool, self._table_row(slot),
+                     jnp.asarray(tok[None]), jnp.int32(start))))
+            elif self._recurrent_chunk:
+                # carried-state dispatch: the chunk's true token count
+                # rides along as a traced scalar — the padded tail of
+                # the final chunk is an exact state no-op (dt masked to
+                # zero), so one compiled program serves full and
+                # partial chunks alike
+                cs.cache1 = self._pin_c1(self._prefill_chunk(
+                    (self.params, cs.cache1, jnp.asarray(tok[None]),
+                     jnp.int32(start), jnp.int32(real))))
+            else:
+                cs.cache1 = self._pin_c1(self._prefill_chunk(
+                    (self.params, cs.cache1, jnp.asarray(tok[None]),
+                     jnp.int32(start))))
         cs.done += real
         self.last_step["chunks"] += 1
         self.policy.charge(cs.req.tenant, 1.0)
@@ -1144,18 +1171,22 @@ class ServingEngine:
         paged engine a FRESH admission reserves its worst-case block
         count up front (the caller checked ``_paged_admissible``), so
         every later ``map_block`` is infallible."""
-        ckpt = self._ckpt.pop(req.uid, None)
-        if ckpt is not None:
-            self._restore_slot(req, slot, ckpt)
-            return
-        if self.paged:
-            need = self._blocks_needed(req)
-            self.pool.reserve(need)
-            self._slot_reserved[slot] = need
-        if self._chunk_eligible(req):
-            self._start_chunked(req, slot)
-        else:
-            self._prefill_one(req, slot)
+        res = self.results[req.uid]
+        if res.admitted_us is None:
+            res.admitted_us = self.clock()
+        with jax.profiler.TraceAnnotation("engine.admit", uid=req.uid):
+            ckpt = self._ckpt.pop(req.uid, None)
+            if ckpt is not None:
+                self._restore_slot(req, slot, ckpt)
+                return
+            if self.paged:
+                need = self._blocks_needed(req)
+                self.pool.reserve(need)
+                self._slot_reserved[slot] = need
+            if self._chunk_eligible(req):
+                self._start_chunked(req, slot)
+            else:
+                self._prefill_one(req, slot)
 
     def _sample(self, logits, temperature: float) -> np.ndarray:
         logits = np.asarray(logits[:, :self.cfg.vocab], np.float32)
@@ -1208,25 +1239,43 @@ class ServingEngine:
         or absorbed by the paged garbage block — and whose token is
         dropped here, never emitted."""
         t0 = time.perf_counter()
-        toks = step.host_fetch()
+        with jax.profiler.TraceAnnotation("engine.readback"):
+            toks = step.host_fetch()
         wait = time.perf_counter() - t0
+        self.last_step["readback_s"] += wait
         eos = self.cfg.vocab - 1
-        for slot, res, req in step.slots:
-            if res.done or self.slot_req[slot] is not res:
-                continue    # retired between dispatch and readback
-            res.decode_s += step.dispatch_s + wait
-            self.policy.charge(req.tenant, 1.0)
-            tok = int(toks[slot])
-            self.slot_budget[slot] -= 1
-            done = self.slot_budget[slot] <= 0 or tok == eos
-            self._emit(res, tok, final=done)
-            if done:
-                res.done = True
-                self.active[slot] = False
-                self.slot_req[slot] = None
-                self.slot_meta[slot] = None
-                if self.paged:
-                    self._release_slot_blocks(slot)
+        with jax.profiler.TraceAnnotation("engine.emit"):
+            for slot, res, req in step.slots:
+                if res.done or self.slot_req[slot] is not res:
+                    continue    # retired between dispatch and readback
+                res.decode_s += step.dispatch_s + wait
+                self.policy.charge(req.tenant, 1.0)
+                tok = int(toks[slot])
+                self.slot_budget[slot] -= 1
+                done = self.slot_budget[slot] <= 0 or tok == eos
+                self._emit(res, tok, final=done)
+                if done:
+                    res.done = True
+                    self.active[slot] = False
+                    self.slot_req[slot] = None
+                    self.slot_meta[slot] = None
+                    if self.paged:
+                        self._release_slot_blocks(slot)
+
+    def _dispatch_decode(self) -> Any:
+        """Dispatch one fused decode step over every slot, keep its
+        cache (ring or paged pool) and return its logits (a device
+        future)."""
+        if self.paged:
+            logits, kv_pool = self._decode(
+                (self.params, self.kv_pool, self.block_tables,
+                 self.cur_tokens, self.lengths))
+            self.kv_pool = self._pin_kv(kv_pool)
+        else:
+            logits, cache = self._decode(
+                (self.params, self.cache, self.cur_tokens, self.lengths))
+            self.cache = self._pin_kv(cache)
+        return logits
 
     def _dispatch_overlapped(self) -> None:
         """Dispatch one fused decode step WITHOUT reading it back, then
@@ -1257,19 +1306,12 @@ class ServingEngine:
                 if len(self._slot_blocks[slot]) != before:
                     self._sync_table_row(slot)
         t0 = time.perf_counter()
-        if self.paged:
-            logits, kv_pool = self._decode(
-                (self.params, self.kv_pool, self.block_tables,
-                 self.cur_tokens, self.lengths))
-            self.kv_pool = self._pin_kv(kv_pool)
-        else:
-            logits, cache = self._decode(
-                (self.params, self.cache, self.cur_tokens, self.lengths))
-            self.cache = self._pin_kv(cache)
-        toks = self._argmax(logits)
-        self.lengths = self.lengths + 1
-        self._len_host += 1
-        self.cur_tokens = self._pin_repl(toks[:, None])
+        with jax.profiler.TraceAnnotation("engine.decode"):
+            logits = self._dispatch_decode()
+            toks = self._argmax(logits)
+            self.lengths = self.lengths + 1
+            self._len_host += 1
+            self.cur_tokens = self._pin_repl(toks[:, None])
         self.last_step["decoded"] = True
         prev, self._inflight = self._inflight, InflightStep(
             tokens=toks,
@@ -1289,9 +1331,22 @@ class ServingEngine:
         The queue is re-keyed at every free slot, so a deadline that
         became urgent while other requests decoded is picked up here;
         with chunking on, a long prompt's prefill is interleaved
-        through these ticks instead of monopolizing the engine."""
+        through these ticks instead of monopolizing the engine.
+
+        ``last_step`` records what the tick did, and splits its wall
+        time into ``readback_s`` (blocked on the device's tokens) and
+        ``host_s`` (everything else)."""
+        t0 = time.perf_counter()
         self.last_step = {"prefill_tokens": [], "chunks": 0,
-                          "decoded": False, "processed": 0}
+                          "decoded": False, "processed": 0,
+                          "readback_s": 0.0, "host_s": 0.0}
+        more = self._tick()
+        self.last_step["host_s"] = (time.perf_counter() - t0
+                                    - self.last_step["readback_s"])
+        return more
+
+    def _tick(self) -> bool:
+        """The body of ``step``."""
         for slot in list(self._chunking):
             self._advance_chunk(slot)
         if self.queue:
@@ -1361,51 +1416,50 @@ class ServingEngine:
             self._dispatch_overlapped()
             return bool(self.active.any() or self.queue
                         or self._chunking or self._inflight is not None)
-        t0 = time.perf_counter()
-        if self.paged:
-            logits, kv_pool = self._decode(
-                (self.params, self.kv_pool, self.block_tables,
-                 self.cur_tokens, self.lengths))
-            self.kv_pool = self._pin_kv(kv_pool)
-        else:
-            logits, cache = self._decode(
-                (self.params, self.cache, self.cur_tokens, self.lengths))
-            self.cache = self._pin_kv(cache)
-        dt = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("engine.decode"):
+            t0 = time.perf_counter()
+            logits = self._dispatch_decode()
+            dt = time.perf_counter() - t0
+            self.lengths = self.lengths + 1
+            self._len_host += 1
         self.last_step["decoded"] = True
-        toks = self._sample(logits, 0.0)
-        self.lengths = self.lengths + 1
-        self._len_host += 1
-        lens_host = np.asarray(self.lengths)
-        new_cur = np.array(self.cur_tokens)    # writable host copy
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("engine.readback"):
+            toks = self._sample(logits, 0.0)
+            lens_host = np.asarray(self.lengths)
+            new_cur = np.array(self.cur_tokens)    # writable host copy
+        self.last_step["readback_s"] += time.perf_counter() - t0
         eos = self.cfg.vocab - 1
-        for slot in range(self.max_slots):
-            if not self.active[slot]:
-                continue
-            res = self.slot_req[slot]
-            res.decode_s += dt
-            self.policy.charge(self.slot_meta[slot].tenant, 1.0)
-            tok = int(toks[slot])
-            self.slot_budget[slot] -= 1
-            new_cur[slot, 0] = tok
-            done = self.slot_budget[slot] <= 0 or tok == eos
-            self._emit(res, tok, final=done)
-            if done:
-                res.done = True
-                self.active[slot] = False
-                self.slot_req[slot] = None
-                self.slot_meta[slot] = None
-                if self.paged:
-                    self._release_slot_blocks(slot)
-            elif self.paged:
-                # grow on demand: map the block the NEXT decode step's
-                # ring write lands in (covered by the reservation)
-                before = len(self._slot_blocks[slot])
-                self._ensure_blocks(
-                    slot, int(lens_host[slot]) % self.cache_len)
-                if len(self._slot_blocks[slot]) != before:
-                    self._sync_table_row(slot)
-        self.cur_tokens = self._pin_repl(jnp.asarray(new_cur))
+        with jax.profiler.TraceAnnotation("engine.emit"):
+            for slot in range(self.max_slots):
+                if not self.active[slot]:
+                    continue
+                res = self.slot_req[slot]
+                res.decode_s += dt
+                self.policy.charge(self.slot_meta[slot].tenant, 1.0)
+                tok = int(toks[slot])
+                self.slot_budget[slot] -= 1
+                new_cur[slot, 0] = tok
+                done = self.slot_budget[slot] <= 0 or tok == eos
+                self._emit(res, tok, final=done)
+                if done:
+                    res.done = True
+                    self.active[slot] = False
+                    self.slot_req[slot] = None
+                    self.slot_meta[slot] = None
+                    if self.paged:
+                        self._release_slot_blocks(slot)
+                elif self.paged:
+                    # grow on demand: map the block the NEXT decode
+                    # step's ring write lands in (covered by the
+                    # reservation)
+                    before = len(self._slot_blocks[slot])
+                    self._ensure_blocks(
+                        slot, int(lens_host[slot]) % self.cache_len)
+                    if len(self._slot_blocks[slot]) != before:
+                        self._sync_table_row(slot)
+        with jax.profiler.TraceAnnotation("engine.decode"):
+            self.cur_tokens = self._pin_repl(jnp.asarray(new_cur))
         return bool(self.active.any() or self.queue or self._chunking)
 
     def run(self, max_steps: int = 10_000) -> Dict[int, RequestResult]:

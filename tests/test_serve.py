@@ -214,8 +214,10 @@ class _FailingEngine:
     """Engine stand-in whose ``step()`` raises once work arrives."""
 
     def __init__(self):
+        from repro.serving import default_clock
         self.on_token = None
         self.results = {}
+        self.clock = default_clock
         self._work = False
 
     def submit(self, req):
