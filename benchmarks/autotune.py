@@ -82,11 +82,10 @@ def _measure_decode_us(bundle, params) -> float:
 
     eng = ServingEngine(bundle, params, max_slots=SLOTS,
                         cache_len=CACHE_LEN, prefill_buckets=False)
-    cache = bundle.empty_cache(SLOTS, CACHE_LEN, bundle.cfg.jnp_dtype())
-    cur = jnp.zeros((SLOTS, 1), jnp.int32)
-    lens = jnp.asarray([8] * SLOTS, jnp.int32)
-    t = measure_compile_and_step(
-        lambda: eng._decode((params, cache, cur, lens)), iters=5)
+    # the serving loop's dispatch: the engine's KV donated and kept
+    eng.cur_tokens = jnp.zeros((SLOTS, 1), jnp.int32)
+    eng.lengths = jnp.asarray([8] * SLOTS, jnp.int32)
+    t = measure_compile_and_step(eng._dispatch_decode, iters=5)
     return t.step_us
 
 

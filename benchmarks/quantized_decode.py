@@ -106,8 +106,8 @@ def _logit_err(cfg, bundle, params, wd: str, kd: str) -> float:
     for _ in range(ERR_STEPS):
         curs = jnp.asarray([[cur]], jnp.int32)
         lens = jnp.asarray([pos], jnp.int32)
-        lf, cf = feng._decode((feng.params, cf, curs, lens))
-        lq, cq = qeng._decode((qeng.params, cq, curs, lens))
+        lf, cf = feng._decode.consume((feng.params, cf, curs, lens))
+        lq, cq = qeng._decode.consume((qeng.params, cq, curs, lens))
         err = max(err, float(jnp.max(jnp.abs(
             lf[:, :cfg.vocab] - lq[:, :cfg.vocab]))))
         cur = int(jnp.argmax(lf[0, :cfg.vocab]))
@@ -153,11 +153,10 @@ def _decode_rate(eng) -> float:
     import jax.numpy as jnp
 
     b = eng.max_slots
-    cur = jnp.zeros((b, 1), jnp.int32)
-    lens = jnp.full((b,), CACHE_LEN // 2, jnp.int32)
-    t = time_call(
-        lambda: block(eng._decode((eng.params, eng.cache, cur, lens))),
-        warmup=2, iters=5)
+    # the serving loop's dispatch: the engine's KV donated and kept
+    eng.cur_tokens = jnp.zeros((b, 1), jnp.int32)
+    eng.lengths = jnp.full((b,), CACHE_LEN // 2, jnp.int32)
+    t = time_call(lambda: block(eng._dispatch_decode()), warmup=2, iters=5)
     return b / t
 
 

@@ -15,11 +15,14 @@ One chip runs these phases in order:
            a contiguous engine serves a few requests, its decode
            program must hold a Pallas kernel (``tpu_custom_call``), its
            first decode logits must agree with a reference-kernel
-           engine's, and a paged engine must emit the same tokens.
+           engine's, and a paged engine must emit the same tokens;
+           one more decode step of each engine must reuse its KV's
+           device buffers (the donation took, nothing copied the KV).
 
 ``--chips 4`` runs the device phase and deepseek-moe-16b on a
 ``ServingEngine`` sharded over a 4-chip mesh, with its first decode
-logits checked against the plain forward of the same weights.
+logits checked against the plain forward of the same weights and its
+decode step's KV kept in place, as above.
 
 Any failed phase makes the exit code 1 and suppresses the result line.
 The last line of a passing run is
@@ -104,6 +107,24 @@ def _rel_err(got, want) -> float:
     got = np.asarray(got, np.float32)
     want = np.asarray(want, np.float32)
     return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def _buffers(tree) -> list:
+    return [[sh.data.unsafe_buffer_pointer() for sh in leaf.addressable_shards]
+            for leaf in jax.tree.leaves(tree)]
+
+
+def _kv_in_place(eng: ServingEngine) -> bool:
+    """One more decode step of ``eng``: true when the step consumed the
+    engine's KV and the engine kept the very device buffers it had, so
+    the donation took and nothing (a changed sharding, the engine's
+    re-pin) copied the KV."""
+    kv = eng.kv_pool if eng.paged else eng.cache
+    before = _buffers(kv)
+    eng._dispatch_decode()
+    after = eng.kv_pool if eng.paged else eng.cache
+    return (all(leaf.is_deleted() for leaf in jax.tree.leaves(kv))
+            and _buffers(after) == before)
 
 
 # ---------------------------------------------------------------------------
@@ -205,15 +226,18 @@ def _requests(cfg, n: int, lens, max_new: int, seed: int = 0):
 
 def _record_first_decode(eng: ServingEngine) -> dict:
     """Keep the inputs, logits and slot requests of ``eng``'s first
-    decode step; ``box["program"]`` is the jitted decode step."""
+    decode step; ``box["program"]`` is the jitted decode step.  The
+    first step is a plain call, which donates a copy of the cache and
+    so leaves the kept inputs valid for a replay; later steps go back
+    to the engine's own dispatch, which consumes the cache in place."""
     box = {"program": eng._decode}
 
     def decode(inputs):
         out = box["program"](inputs)
-        if "logits" not in box:
-            box.update(inputs=inputs, logits=out[0],
-                       reqs=[eng.slot_meta[s] if eng.active[s] else None
-                             for s in range(eng.max_slots)])
+        box.update(inputs=inputs, logits=out[0],
+                   reqs=[eng.slot_meta[s] if eng.active[s] else None
+                         for s in range(eng.max_slots)])
+        eng._decode = box["program"]
         return out
 
     eng._decode = decode
@@ -263,6 +287,7 @@ def serve_phase(cfg, *, n_requests: int = 8, slots: int = 4,
                           tags=PALLAS)
     paged_tokens, paged_wall = _serve(paged, reqs)
     same = paged_tokens == tokens
+    in_place = [_kv_in_place(eng), _kv_in_place(paged)]
     _say("serve", arch=cfg.arch_id, layers=cfg.n_layers,
          d_model=cfg.d_model, vocab=cfg.vocab, dtype=cfg.dtype,
          param_bytes=eng.param_bytes, kv_bytes=eng.kv_bytes,
@@ -274,7 +299,7 @@ def serve_phase(cfg, *, n_requests: int = 8, slots: int = 4,
          kernel_rel_err=kernel_err, kernel_rel_tol=KERNEL_RTOL,
          first_decode_rel_err=err, rel_tol=LOGIT_RTOL,
          paged_kv_block=kv_block, paged_wall_s=round(paged_wall, 3),
-         paged_tokens_identical=same,
+         paged_tokens_identical=same, kv_in_place=in_place,
          compiles=_compiles_since(mark), peak_bytes=_peak_bytes())
     if has_kernel == kernel_ops.interpret_mode():
         raise AssertionError(
@@ -291,6 +316,9 @@ def serve_phase(cfg, *, n_requests: int = 8, slots: int = 4,
                              f"{err} > {LOGIT_RTOL}")
     if not same:
         raise AssertionError("paged engine tokens differ from contiguous")
+    if not all(in_place):
+        raise AssertionError(f"decode step copied its KV (contiguous, "
+                             f"paged in place: {in_place})")
 
 
 def sharded_phase(cfg, chips: int, *, slots: int = 4,
@@ -322,6 +350,7 @@ def sharded_phase(cfg, chips: int, *, slots: int = 4,
     box = _record_first_decode(eng)
     tokens, wall = _serve(eng, reqs)
     n_tok = sum(len(t) for t in tokens.values())
+    in_place = _kv_in_place(eng)
 
     rows = [s for s, r in enumerate(box["reqs"]) if r is not None]
     seqs = np.stack([box["reqs"][s].tokens for s in rows])
@@ -340,8 +369,8 @@ def sharded_phase(cfg, chips: int, *, slots: int = 4,
          tokens_generated=n_tok, kernel_rel_err=kernel_err,
          kernel_rel_tol=KERNEL_RTOL,
          first_decode_vs_forward_rel_err=err,
-         rel_tol=LOGIT_RTOL, compiles=_compiles_since(mark),
-         peak_bytes_per_device=peaks)
+         rel_tol=LOGIT_RTOL, kv_in_place=in_place,
+         compiles=_compiles_since(mark), peak_bytes_per_device=peaks)
     if max(per_dev.values()) >= eng.param_bytes:
         raise AssertionError("one device holds the whole model")
     if not n_tok == slots * max_new:
@@ -352,6 +381,8 @@ def sharded_phase(cfg, chips: int, *, slots: int = 4,
     if not err <= LOGIT_RTOL:
         raise AssertionError(f"sharded decode vs forward logits: "
                              f"{err} > {LOGIT_RTOL}")
+    if not in_place:
+        raise AssertionError("sharded decode step copied its KV")
 
 
 # ---------------------------------------------------------------------------

@@ -280,41 +280,23 @@ class EngineMeasurer:
                 args = (self.params, cache1, toks, jnp.int32(0))
             return measure_compile_and_step(
                 lambda: eng._prefill_chunk(args), iters=self.iters)
-        if kind == "decode":
-            # one fused decode dispatch at `size` concurrent slots —
-            # half-full caches so masking work is representative
-            eng = self._aux(kind, int(size))
-            b = int(size)
-            cur = jnp.zeros((b, 1), jnp.int32)
-            lens = jnp.full((b,), self.cache_len // 2, jnp.int32)
-            return measure_compile_and_step(
-                lambda: eng._decode((self.params, eng.cache, cur, lens)),
-                iters=self.iters)
-        if kind.startswith("decode_q:"):
-            # quantized fused decode at `size` slots — the kind string
-            # carries the precision pair ("decode_q:<weight>:<kv>",
-            # "fp32" = that axis unquantized) so injected hooks keep
-            # the flat (kind, size) measurement contract
-            eng = self._aux(kind, int(size))
-            b = int(size)
-            cur = jnp.zeros((b, 1), jnp.int32)
-            lens = jnp.full((b,), self.cache_len // 2, jnp.int32)
-            return measure_compile_and_step(
-                lambda: eng._decode((eng.params, eng.cache, cur, lens)),
-                iters=self.iters)
-        if kind == "decode_paged":
-            # one paged decode dispatch with `size`-row KV blocks; the
-            # engine's freshly-zeroed pool and garbage tables are fine
-            # here — timing depends on shapes, not on which blocks the
-            # tables point at
+        if kind in ("decode", "decode_paged") \
+                or kind.startswith("decode_q:"):
+            # one fused decode dispatch as the serving loop makes it
+            # (the engine's KV donated and the returned one kept) at
+            # half-full lengths, so masking work is representative:
+            # `size` slots for "decode" and quantized "decode_q:<weight>
+            # :<kv>" ("fp32" = that axis unquantized, so injected hooks
+            # keep the flat (kind, size) contract); `size`-row blocks
+            # for "decode_paged", whose zeroed pool and garbage tables
+            # are fine — timing depends on shapes, not on which blocks
+            # the tables point at
             eng = self._aux(kind, int(size))
             b = eng.max_slots
-            cur = jnp.zeros((b, 1), jnp.int32)
-            lens = jnp.full((b,), self.cache_len // 2, jnp.int32)
-            return measure_compile_and_step(
-                lambda: eng._decode((self.params, eng.kv_pool,
-                                     eng.block_tables, cur, lens)),
-                iters=self.iters)
+            eng.cur_tokens = jnp.zeros((b, 1), jnp.int32)
+            eng.lengths = jnp.full((b,), self.cache_len // 2, jnp.int32)
+            return measure_compile_and_step(eng._dispatch_decode,
+                                            iters=self.iters)
         raise ValueError(f"unknown measurement kind {kind!r}")
 
     def _aux(self, kind: str, size: int):

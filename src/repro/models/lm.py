@@ -229,6 +229,30 @@ def attention_block(p: Params, cfg: ModelConfig, x, *,
 # decode attention (one token, ring KV cache)
 # ---------------------------------------------------------------------------
 
+def _attend_ring(p: Params, cfg: ModelConfig, q, kc, vc, n_valid, dtype,
+                 attn_impl=None) -> jnp.ndarray:
+    """q (B,1,H,dh) over one layer's ring kc/vc (B,KH,C,dh), masked to
+    the first ``n_valid`` (B,) positions, then the output projection:
+    (B,1,D).  ``attn_impl`` replaces the einsum attention (§4.8)."""
+    b = q.shape[0]
+    h, kh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.dh
+    g = h // kh
+    c = kc.shape[2]
+    if attn_impl is not None:
+        out = attn_impl(q[:, 0], kc, vc, n_valid).reshape(b, 1, h, dh)
+    else:
+        qg = q[:, 0].reshape(b, kh, g, dh)
+        scale = 1.0 / math.sqrt(dh)
+        logits = jnp.einsum("bkgd,bkcd->bkgc", qg, kc,
+                            preferred_element_type=jnp.float32) * scale
+        pos = jnp.arange(c)[None, None, None, :]
+        valid = pos < n_valid[:, None, None, None]
+        logits = jnp.where(valid, logits, -1e30)
+        w = jax.nn.softmax(logits, axis=-1).astype(dtype)
+        out = jnp.einsum("bkgc,bkcd->bkgd", w, vc).reshape(b, 1, h, dh)
+    return jnp.einsum("bqhk,hkd->bqd", out, p["wo"])
+
+
 def decode_attention_block(p: Params, cfg: ModelConfig, x, cache_k, cache_v,
                            lengths, attn_impl=None
                            ) -> Tuple[jnp.ndarray, jnp.ndarray,
@@ -242,9 +266,6 @@ def decode_attention_block(p: Params, cfg: ModelConfig, x, cache_k, cache_v,
     ``attn_impl(q (B,H,dh), kc, vc, n_valid) -> (B,H,dh)`` over the
     already-updated cache; the ring update and output projection stay
     identical to the reference path."""
-    b = x.shape[0]
-    h, kh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.dh
-    g = h // kh
     c = cache_k.shape[2]
     q, k, v = _proj_qkv(p, cfg, x, lengths[:, None])
     slot = (lengths % c).astype(jnp.int32)
@@ -254,20 +275,44 @@ def decode_attention_block(p: Params, cfg: ModelConfig, x, cache_k, cache_v,
     vc = cache_v * (1 - onehot)[:, None, :, None] \
         + v[:, 0][:, :, None, :] * onehot[:, None, :, None]
     n_valid = jnp.minimum(lengths + 1, c)
-    if attn_impl is not None:
-        out = attn_impl(q[:, 0], kc, vc, n_valid).reshape(b, 1, h, dh)
-    else:
-        qg = q[:, 0].reshape(b, kh, g, dh)
-        scale = 1.0 / math.sqrt(dh)
-        logits = jnp.einsum("bkgd,bkcd->bkgc", qg, kc,
-                            preferred_element_type=jnp.float32) * scale
-        pos = jnp.arange(c)[None, None, None, :]
-        valid = pos < n_valid[:, None, None, None]
-        logits = jnp.where(valid, logits, -1e30)
-        w = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bkgc,bkcd->bkgd", w, vc).reshape(b, 1, h, dh)
-    y = jnp.einsum("bqhk,hkd->bqd", out, p["wo"])
+    y = _attend_ring(p, cfg, q, kc, vc, n_valid, x.dtype, attn_impl)
     return y, kc, vc
+
+
+def _write_rows(cache, rows, layer, slot):
+    """Write ``rows`` (B,KH,dh) into the stacked ring cache
+    (L,B,KH,C,dh) at ``(layer, b, :, slot[b], :)``: one
+    dynamic-update-slice per slot over the static B, which XLA does in
+    place on a carried, donated buffer.  (A batched ``.at[].set``
+    scatter instead led XLA to a transposed cache layout and two
+    whole-cache copies a step.)"""
+    upd = rows.astype(cache.dtype)[:, None, None, :, None, :]
+    for b in range(rows.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, upd[b], (layer, b, 0, slot[b], 0))
+    return cache
+
+
+def decode_attention_layer(p: Params, cfg: ModelConfig, x, cache_k,
+                           cache_v, layer, lengths, attn_impl=None
+                           ) -> Tuple[jnp.ndarray, jnp.ndarray,
+                                      jnp.ndarray]:
+    """``decode_attention_block`` for layer ``layer`` (a traced int32) of
+    the stacked ring cache_k/v (L,B,KH,C,dh): the new token's K/V rows
+    are written in place at ring position ``lengths % C`` — every
+    slot's, inactive ones included — and attention reads that layer's
+    view.  Returns (out (B,1,D), cache_k, cache_v), bit-identical to
+    the one-hot update (an IEEE ``old*1 + new*0`` is ``old``)."""
+    c = cache_k.shape[3]
+    q, k, v = _proj_qkv(p, cfg, x, lengths[:, None])
+    slot = (lengths % c).astype(jnp.int32)
+    cache_k = _write_rows(cache_k, k[:, 0], layer, slot)
+    cache_v = _write_rows(cache_v, v[:, 0], layer, slot)
+    kc = jax.lax.dynamic_index_in_dim(cache_k, layer, keepdims=False)
+    vc = jax.lax.dynamic_index_in_dim(cache_v, layer, keepdims=False)
+    n_valid = jnp.minimum(lengths + 1, c)
+    y = _attend_ring(p, cfg, q, kc, vc, n_valid, x.dtype, attn_impl)
+    return y, cache_k, cache_v
 
 
 # ---------------------------------------------------------------------------
@@ -817,42 +862,39 @@ def lm_decode(params: Params, cfg: ModelConfig, cache: Dict, tokens,
               embed_scale: Optional[float] = None, attn_impl=None):
     """One decode step.  tokens (B,1); lengths (B,) absolute positions;
     cache {k,v}: (L,B,KH,C,dh).  Returns (logits (B,V), new_cache).
-    ``attn_impl`` plumbs a vendor attention kernel into every layer's
-    decode_attention_block (§4.8)."""
+    The layer scan carries the stacked cache and each layer writes its
+    new rows in place (``decode_attention_layer``), so on a donated
+    cache the step moves only those rows.  ``attn_impl`` plumbs a
+    vendor attention kernel into every layer (§4.8)."""
     x = embed_tokens(params, cfg, tokens)
     if embed_scale is not None:
         x = x * jnp.asarray(embed_scale, x.dtype)
-    i0 = 0
-    if "first_block" in params:
-        fb = jax.tree.map(lambda a: a[0], params["first_block"])
-        xin = rms_norm(x, fb["ln1"], cfg.norm_eps)
-        att, kc, vc = decode_attention_block(fb["attn"], cfg, xin,
-                                             cache["k"][0], cache["v"][0],
-                                             lengths, attn_impl=attn_impl)
-        h = x + att
-        hin = rms_norm(h, fb["ln2"], cfg.norm_eps)
-        x = h + mlp_block(fb["mlp"], cfg, hin)
-        first_kv = (kc, vc)
-        i0 = 1
 
-    def body(h, layer_in):
-        p_l, ck, cv = layer_in
+    def layer(h, p_l, ck, cv, i):
         xin = rms_norm(h, p_l["ln1"], cfg.norm_eps)
-        att, kc, vc = decode_attention_block(p_l["attn"], cfg, xin, ck, cv,
-                                             lengths, attn_impl=attn_impl)
+        att, ck, cv = decode_attention_layer(p_l["attn"], cfg, xin, ck, cv,
+                                             i, lengths, attn_impl=attn_impl)
         hh = h + att
         hin = rms_norm(hh, p_l["ln2"], cfg.norm_eps)
         if "moe" in p_l:
             y, _ = moe_block(p_l["moe"], cfg, hin, data_shards)
         else:
             y = mlp_block(p_l["mlp"], cfg, hin)
-        return hh + y, (kc, vc)
+        return hh + y, ck, cv
 
-    x, (ks_, vs_) = jax.lax.scan(body, x,
-                                 (params["blocks"], cache["k"][i0:],
-                                  cache["v"][i0:]))
-    if i0:
-        ks_ = jnp.concatenate([first_kv[0][None], ks_])
-        vs_ = jnp.concatenate([first_kv[1][None], vs_])
+    ck, cv = cache["k"], cache["v"]
+    i0 = 0
+    if "first_block" in params:
+        fb = jax.tree.map(lambda a: a[0], params["first_block"])
+        x, ck, cv = layer(x, fb, ck, cv, jnp.int32(0))
+        i0 = 1
+
+    def body(carry, p_l):
+        h, ck, cv, i = carry
+        h, ck, cv = layer(h, p_l, ck, cv, i)
+        return (h, ck, cv, i + 1), None
+
+    (x, ck, cv, _), _ = jax.lax.scan(body, (x, ck, cv, jnp.int32(i0)),
+                                     params["blocks"])
     logits = lm_logits(params, cfg, x)[:, 0]
-    return logits, {"k": ks_, "v": vs_}
+    return logits, {"k": ck, "v": cv}

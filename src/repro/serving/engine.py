@@ -24,9 +24,14 @@ Compile-once invariants (what callers may rely on):
     bucketing is active (the default for dense/vlm/moe) and once per
     distinct prompt length otherwise.  Model family, cache layout,
     slot count, and window are baked in then.
-  * **donated** — nothing in this engine: the KV cache and sampling
-    state are carried functionally (cache in, cache out) so a step can
-    be replayed; the ARENA accounts capacity (KV is an
+  * **donated** — the KV state (the contiguous ring or the paged
+    pool), and nothing else, into the decode program
+    (``DecodeStep.consume``): the step updates it in place and the
+    engine keeps only the KV it hands back, so a step holds one copy
+    of the cache, not its input and its output.  A plain call of the
+    step (``engine._decode(inputs)``) donates a copy instead, so a
+    replay or a wrapper keeps what it passed.  Sampling state and
+    weights are never donated; the ARENA accounts capacity (KV is an
     interpreter-lifetime tail allocation) but does not back device
     buffers here.
   * **may vary per call** — token values, per-slot lengths, and which
@@ -296,6 +301,56 @@ class _ChunkState:
 
 def _cache_bytes(tree: Any) -> int:
     return sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(tree))
+
+
+def _split_step(step, kv_sharding, params, kv, rest):
+    logits, kv = step((params, kv, *rest))
+    if kv_sharding is not None:
+        kv = jax.lax.with_sharding_constraint(kv, kv_sharding)
+    return logits, kv
+
+
+class DecodeStep:
+    """The engine's decode program: ``step`` — a decode registration's
+    ``eval`` with its context and op bound — called with one tuple
+    ``(params, kv, *rest)``, jitted once with only ``kv`` (the ring or
+    the paged pool) donated.  On a mesh, ``kv_sharding`` (the engine's
+    KV sharding) holds the KV the step hands back to the placement it
+    came in on, so the donated buffers are reused: left to itself,
+    GSPMD may return another placement (a replicated paged pool, with
+    fewer KV heads than devices), which the engine's re-pin would then
+    copy every step.
+
+    ``consume(inputs)`` gives the program the caller's KV, which it
+    updates in place: the caller must drop it and keep the KV it gets
+    back.  A plain call, ``step(inputs)``, donates a copy, so what it
+    was handed stays valid (a replay, a recorder, a test's wrapper);
+    it costs one pass over the whole KV.  Both run the same compiled
+    program (``jit_cache_size`` reads it)."""
+
+    def __init__(self, step, kv_sharding=None):
+        self._jit = jax.jit(functools.partial(_split_step, step,
+                                              kv_sharding),
+                            donate_argnums=1)
+
+    def consume(self, inputs):
+        """Run on ``inputs``, donating its KV; returns (logits, kv)."""
+        params, kv, *rest = inputs
+        return self._jit(params, kv, tuple(rest))
+
+    def __call__(self, inputs):
+        """Run on a copy of ``inputs``' KV, which stays valid: one more
+        pass over the whole KV than ``consume``."""
+        params, kv, *rest = inputs
+        return self.consume((params, jax.tree.map(jnp.copy, kv), *rest))
+
+    def lower(self, inputs):
+        """Lower the program for ``inputs`` (arrays or shapes)."""
+        params, kv, *rest = inputs
+        return self._jit.lower(params, kv, tuple(rest))
+
+    def _cache_size(self) -> int:
+        return self._jit._cache_size()
 
 
 class ServingEngine:
@@ -581,8 +636,9 @@ class ServingEngine:
             bundle, prefill_reg.prepare(pctx, self._prefill_op).op_data)
         decode_ctx = serving_ops.ServingContext(
             bundle, decode_reg.prepare(pctx, self._decode_op).op_data)
-        self._decode = jax.jit(functools.partial(
-            decode_reg.eval, decode_ctx, self._decode_op))
+        self._decode = DecodeStep(
+            functools.partial(decode_reg.eval, decode_ctx, self._decode_op),
+            self._shard["cache"] if self._shard else None)
         # overlap mode's device-side greedy sampler: its OWN tiny jitted
         # program (the decode program stays byte-for-byte the one sync
         # mode runs, so jit_cache_size(self._decode) == 1 holds either
@@ -1263,16 +1319,20 @@ class ServingEngine:
                         self._release_slot_blocks(slot)
 
     def _dispatch_decode(self) -> Any:
-        """Dispatch one fused decode step over every slot, keep its
-        cache (ring or paged pool) and return its logits (a device
-        future)."""
+        """Dispatch one fused decode step over every slot, donating the
+        cache (ring or paged pool) and keeping the one it returns;
+        return its logits (a device future).  A step someone has
+        wrapped (a recorder, an injected fault) gets the plain call,
+        which leaves what it is handed valid."""
+        step = self._decode
+        run = step.consume if isinstance(step, DecodeStep) else step
         if self.paged:
-            logits, kv_pool = self._decode(
+            logits, kv_pool = run(
                 (self.params, self.kv_pool, self.block_tables,
                  self.cur_tokens, self.lengths))
             self.kv_pool = self._pin_kv(kv_pool)
         else:
-            logits, cache = self._decode(
+            logits, cache = run(
                 (self.params, self.cache, self.cur_tokens, self.lengths))
             self.cache = self._pin_kv(cache)
         return logits

@@ -53,8 +53,8 @@ Compile-once invariants this module maintains:
     (admission order) are host-side Python over the queues; they can
     never invalidate a trace.
   * **donated** — micro arena buffers and variable stacks cycle through
-    the shared ``ArenaPool``; engine caches are carried functionally
-    through the jitted decode step.
+    the shared ``ArenaPool``; each engine donates its KV cache into
+    its jitted decode step, which updates it in place.
   * **may vary per call** — request content (tokens, frames), slot/lane
     occupancy masks, and step counters.  Admitting a TENANT (a new
     model) is the only act that allocates or compiles; admitting a

@@ -46,6 +46,7 @@ def test_serve_phase_reduced(smoke, capsys):
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["tokens_generated"] == 8 * 6
     assert line["paged_tokens_identical"]
+    assert line["kv_in_place"] == [True, True]
     assert not line["decode_hlo_has_tpu_custom_call"]   # interpreted
 
 
@@ -71,6 +72,7 @@ def test_sharded_phase_four_host_devices():
     assert line["phase"] == "sharded" and line["chips"] == 4
     assert max(line["param_bytes_per_device"]) < line["param_bytes"]
     assert line["first_decode_vs_forward_rel_err"] <= line["rel_tol"]
+    assert line["kv_in_place"]
 
 
 def test_no_result_without_tpu(smoke, capsys):

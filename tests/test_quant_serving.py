@@ -225,8 +225,8 @@ def _logit_err(family, wd, kd, steps=4):
     for _ in range(steps):
         curs = jnp.asarray([[cur]], jnp.int32)
         lens = jnp.asarray([pos], jnp.int32)
-        lf, cf = feng._decode((feng.params, cf, curs, lens))
-        lq, cq = qeng._decode((qeng.params, cq, curs, lens))
+        lf, cf = feng._decode.consume((feng.params, cf, curs, lens))
+        lq, cq = qeng._decode.consume((qeng.params, cq, curs, lens))
         err = max(err, float(jnp.max(jnp.abs(
             lf[:, :cfg.vocab] - lq[:, :cfg.vocab]))))
         cur = int(jnp.argmax(lf[0, :cfg.vocab]))
