@@ -330,10 +330,6 @@ def sharded_phase(cfg, chips: int, *, slots: int = 4,
     each prompt but its last token, which the first decode step
     consumes, so that step's logits are the forward's last-position
     logits over the whole prompt."""
-    # dropless routing (capacity covers every token): the engine's
-    # prefill and decode and the reference forward route identically
-    cfg = dataclasses.replace(cfg,
-                              capacity_factor=cfg.n_experts / cfg.top_k)
     mark = len(_COMPILES)
     mesh = make_serving_mesh(chips)
     t0 = time.perf_counter()

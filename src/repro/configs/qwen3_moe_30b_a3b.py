@@ -22,6 +22,7 @@ CONFIG = ModelConfig(
     top_k=8,
     moe_d_ff=768,
     act='silu',
+    norm_topk_prob=True,   # the published config.json: true
     rope_base=1000000.0,
     sliding_window=8192,
     source='hf:Qwen/Qwen3-30B-A3B',

@@ -44,7 +44,8 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_d_ff: int = 0
     first_layer_dense_ff: int = 0   # deepseek: dense layer 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # training only: serving is dropless
+    norm_topk_prob: bool = True     # renormalise the top-k gate weights
     # SSM (Mamba-2)
     ssm_state: int = 0
     ssm_head_dim: int = 64

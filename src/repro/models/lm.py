@@ -19,6 +19,10 @@ Design notes (TPU-native, see DESIGN.md §6):
     grouped by data shard, top-k routed, gathered to (G, E, C, D) and
     expert-matmul'd with experts sharded on the ``model`` axis — the
     (data → model) reshard of the dispatch tensor is the all-to-all.
+    Training (``lm_loss``) caps each expert at ``capacity_factor``;
+    the serving steps (prefill, chunk, decode) are dropless: each
+    expert's capacity is every token of the group, so a request's
+    output never depends on what the other slots route.
 """
 
 from __future__ import annotations
@@ -492,31 +496,39 @@ def moe_groups(n_tokens: int, data_shards: int = 16) -> int:
     return 1
 
 
-def moe_capacity(cfg: ModelConfig, tokens_per_group: int) -> int:
+def moe_capacity(cfg: ModelConfig, tokens_per_group: int,
+                 capped: bool = False) -> int:
+    """Queue slots per expert and group: every token of the group (a
+    token picks an expert at most once, so nothing is dropped), or when
+    ``capped`` (training) ``capacity_factor`` times the fair share, at
+    least 4 and a multiple of 4."""
+    if not capped:
+        return tokens_per_group
     c = int(math.ceil(tokens_per_group * cfg.top_k * cfg.capacity_factor
                       / cfg.n_experts))
     return max(4, -(-c // 4) * 4)          # >=4, multiple of 4
 
 
 def moe_dispatch(router_logits, cfg: ModelConfig, capacity: int,
-                 n_valid=None, eff_capacity=None):
+                 n_valid=None):
     """router_logits (G,T,E) -> (dispatch_idx (G,E*C) int32 token ids
-    [T = dropped], combine (G,E*C) weights, aux_loss scalar).
+    [T = dropped], combine (G,E*C) weights, aux_loss scalar).  The
+    combine weights are the top-k softmax probabilities, renormalised
+    to sum to 1 only when ``cfg.norm_topk_prob``.
 
-    Capacity-stable masked mode (bucketed MoE prefill): when
-    ``n_valid`` / ``eff_capacity`` are given (TRACED scalars), T is a
-    right-PADDED token count and ``capacity`` the bucket's python-int
-    capacity — the compiled shape.  Tokens at flat positions >=
-    ``n_valid`` are dropped outright and real tokens keep only queue
-    positions < ``eff_capacity`` (the true length's capacity), so the
-    kept set — and, because right-padding appends to the END of the
-    cumsum order, every kept token's queue position — is exactly what
-    the unpadded dispatch at the true length computes.  One compile
-    per bucket, bit-identical expert routing per true length."""
+    Masked mode (bucketed MoE prefill): when ``n_valid`` (a TRACED
+    scalar) is given, T is a right-PADDED token count — the compiled
+    shape — and tokens at flat positions >= ``n_valid`` are dropped
+    outright.  Right-padding appends to the END of the cumsum order,
+    so with a dropless capacity (every token of the bucket) each real
+    token keeps exactly the queue position the unpadded dispatch at
+    the true length gives it: one compile per bucket, bit-identical
+    expert routing per true length."""
     g, t, e = router_logits.shape
     probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
     top_w, top_ids = jax.lax.top_k(probs, cfg.top_k)          # (G,T,K)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
 
     # load-balance auxiliary loss (Switch):  E * sum_e f_e * p_e
     density = jnp.mean(jax.nn.one_hot(top_ids[..., 0], e), axis=1)   # (G,E)
@@ -531,11 +543,9 @@ def moe_dispatch(router_logits, cfg: ModelConfig, capacity: int,
     pos = jnp.take_along_axis(pos_in_e, flat_ids[..., None],
                               axis=-1)[..., 0]                # (G,TK)
     token_of = jnp.arange(t * cfg.top_k) // cfg.top_k         # (TK,)
+    keep = pos < capacity
     if n_valid is not None:
-        cap_eff = capacity if eff_capacity is None else eff_capacity
-        keep = (pos < cap_eff) & (token_of[None, :] < n_valid)
-    else:
-        keep = pos < capacity
+        keep = keep & (token_of[None, :] < n_valid)
     slot = flat_ids * capacity + pos                          # (G,TK)
     slot = jnp.where(keep, slot, e * capacity)                # overflow bin
     # scatter token ids into slots; default T = dummy token
@@ -551,70 +561,76 @@ def moe_dispatch(router_logits, cfg: ModelConfig, capacity: int,
 
 def moe_block(p: Params, cfg: ModelConfig, x,
               data_shards: int = 16, n_valid=None,
-              eff_capacity=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+              capped: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x (B,S,D) -> (y, aux_loss).  Expert-parallel capacity dispatch.
+    Every expert's queue holds the group's tokens, so no (token, expert)
+    pair is dropped; ``capped`` (training's ``_layer_fwd``) caps it at
+    ``capacity_factor`` instead.
 
-    When an activation-sharding context is active and shapes divide,
-    delegates to the shard_map all-to-all implementation (§Perf C4) —
-    explicit EP collectives instead of GSPMD-inferred ones.
+    When capped, an activation-sharding context is active and shapes
+    divide, delegates to the shard_map all-to-all implementation
+    (§Perf C4) — explicit EP collectives instead of GSPMD-inferred ones.
 
-    ``n_valid`` / ``eff_capacity`` (TRACED scalars) switch
-    ``moe_dispatch`` into its capacity-stable masked mode for
-    bucketed-prefill serving: S is a right-padded bucket length and
-    expert capacity a function of the BUCKET (the compiled shape)
-    while the dispatch masks to the true length's capacity — see
+    ``n_valid`` (a TRACED scalar) switches ``moe_dispatch`` into its
+    masked mode for bucketed-prefill serving: S is a right-padded
+    bucket length and only the first ``n_valid`` tokens route — see
     ``moe_dispatch``.  Masked mode keeps the single-group layout
     (token positions across groups would not survive padding)."""
     b, s, d = x.shape
     from .moe_ep import ep_applicable, moe_block_ep
-    if n_valid is None and ep_applicable(cfg, b, s):
+    if n_valid is None and capped and ep_applicable(cfg, b, s):
         return moe_block_ep(p, cfg, x)
     t_all = b * s
     g = moe_groups(t_all, data_shards)
     if n_valid is not None and g != 1:
-        raise ValueError("capacity-stable masked dispatch requires the "
-                         "single-group layout (got %d groups)" % g)
+        raise ValueError("masked dispatch requires the single-group "
+                         "layout (got %d groups)" % g)
     t = t_all // g
     xg = shard_group(x.reshape(g, t, d))
-    cap = moe_capacity(cfg, t)
-    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32), p["router"])
-    dispatch, combine, aux = moe_dispatch(logits, cfg, cap,
-                                          n_valid=n_valid,
-                                          eff_capacity=eff_capacity)
-    # pad a zero token row for dropped/dummy slots
-    xpad = jnp.concatenate([xg, jnp.zeros((g, 1, d), xg.dtype)], axis=1)
-    xe = jnp.take_along_axis(xpad, dispatch[..., None], axis=1)  # (G,EC,D)
-    # pin dispatch tensors expert-parallel: groups on data, experts on
-    # model — the reshard from token-grouped to expert-parallel IS the
-    # all-to-all; without the pins GSPMD replicates (§Perf C2)
-    xe = shard_expert(xe.reshape(g, cfg.n_experts, cap, d))
-    we = p["experts"]
-    wi = gather_expert_weights(we["wi"])
-    wo = gather_expert_weights(we["wo"])
-    hid = jnp.einsum("gecd,edf->gecf", xe, wi)
-    if cfg.act in GATED_ACTS:
-        gate = jnp.einsum("gecd,edf->gecf", xe,
-                          gather_expert_weights(we["wg"]))
-        hid = _gate(cfg.act, gate) * hid
-    else:
-        hid = jax.nn.gelu(hid)
-    hid = shard_expert(hid)
-    ye = shard_expert(jnp.einsum("gecf,efd->gecd", hid, wo))
-    ye = (ye.reshape(g, cfg.n_experts * cap, d)
-          * combine[..., None].astype(ye.dtype))
-    # combine back: scatter-add slots to tokens
-    ypad = jnp.zeros((g, t + 1, d), ye.dtype)
-    y = shard_group(
-        ypad.at[jnp.arange(g)[:, None], dispatch].add(ye)[:, :t])
-    if cfg.n_shared_experts:
-        sh = p["shared"]
-        hid = jnp.einsum("gtd,df->gtf", xg, sh["wi"])
+    cap = moe_capacity(cfg, t, capped)
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
+                            p["router"])
+        dispatch, combine, aux = moe_dispatch(logits, cfg, cap,
+                                              n_valid=n_valid)
+    with jax.named_scope("moe.experts"):
+        # pad a zero token row for dropped/dummy slots
+        xpad = jnp.concatenate([xg, jnp.zeros((g, 1, d), xg.dtype)],
+                               axis=1)
+        xe = jnp.take_along_axis(xpad, dispatch[..., None],
+                                 axis=1)                     # (G,EC,D)
+        # pin dispatch tensors expert-parallel: groups on data, experts
+        # on model — the reshard from token-grouped to expert-parallel
+        # IS the all-to-all; without the pins GSPMD replicates (§Perf C2)
+        xe = shard_expert(xe.reshape(g, cfg.n_experts, cap, d))
+        we = p["experts"]
+        wi = gather_expert_weights(we["wi"])
+        wo = gather_expert_weights(we["wo"])
+        hid = jnp.einsum("gecd,edf->gecf", xe, wi)
         if cfg.act in GATED_ACTS:
-            gate = jnp.einsum("gtd,df->gtf", xg, sh["wg"])
+            gate = jnp.einsum("gecd,edf->gecf", xe,
+                              gather_expert_weights(we["wg"]))
             hid = _gate(cfg.act, gate) * hid
         else:
             hid = jax.nn.gelu(hid)
-        y = y + jnp.einsum("gtf,fd->gtd", hid, sh["wo"])
+        hid = shard_expert(hid)
+        ye = shard_expert(jnp.einsum("gecf,efd->gecd", hid, wo))
+        ye = (ye.reshape(g, cfg.n_experts * cap, d)
+              * combine[..., None].astype(ye.dtype))
+        # combine back: scatter-add slots to tokens
+        ypad = jnp.zeros((g, t + 1, d), ye.dtype)
+        y = shard_group(
+            ypad.at[jnp.arange(g)[:, None], dispatch].add(ye)[:, :t])
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe.shared"):
+            sh = p["shared"]
+            hid = jnp.einsum("gtd,df->gtf", xg, sh["wi"])
+            if cfg.act in GATED_ACTS:
+                gate = jnp.einsum("gtd,df->gtf", xg, sh["wg"])
+                hid = _gate(cfg.act, gate) * hid
+            else:
+                hid = jax.nn.gelu(hid)
+            y = y + jnp.einsum("gtf,fd->gtd", hid, sh["wo"])
     return y.reshape(b, s, d), aux
 
 
@@ -634,7 +650,7 @@ def _layer_fwd(cfg: ModelConfig, x, p_l, *, prefix_len=0, window=None,
     h = shard_seq(h)
     hin = rms_norm(h, p_l["ln2"], cfg.norm_eps)
     if "moe" in p_l:
-        y, aux = moe_block(p_l["moe"], cfg, hin, data_shards)
+        y, aux = moe_block(p_l["moe"], cfg, hin, data_shards, capped=True)
     elif "mlp" in p_l:
         y, aux = mlp_block(p_l["mlp"], cfg, hin), 0.0
     return shard_seq(h + y), aux
@@ -700,18 +716,17 @@ def lm_prefill(params: Params, cfg: ModelConfig, tokens,
                prefix_len: int = 0, data_shards: int = 16,
                prefix_embed: Optional[jnp.ndarray] = None,
                embed_scale: Optional[float] = None,
-               n_valid=None, moe_cap=None):
+               n_valid=None):
     """tokens (B,S) -> (last-token logits (B,V), cache dict).
 
     cache layout: k/v (L, B, KH, C, dh) ring-indexed by absolute pos.
     ``prefix_embed`` (B,P,D) prepends already-embedded tokens (VLM
     vision prefix); combined with ``prefix_len`` for prefix-LM masking.
-    ``n_valid`` / ``moe_cap`` (TRACED scalars) are the capacity-stable
-    bucketed-MoE mode: S is a right-padded bucket length, ``n_valid``
-    the true token count and ``moe_cap`` the true length's expert
-    capacity — threaded into every ``moe_block`` so expert capacity is
-    a function of the bucket shape, not the true length (one compile
-    per bucket; see ``moe_dispatch``).
+    ``n_valid`` (a TRACED scalar) is the bucketed-MoE mode: S is a
+    right-padded bucket length and ``n_valid`` the true token count;
+    every ``moe_block`` routes only the first ``n_valid`` tokens, with
+    dropless capacity, so one compile per bucket routes exactly what
+    the true length routes (see ``moe_dispatch``).
     """
     x = embed_tokens(params, cfg, tokens)
     if embed_scale is not None:
@@ -732,7 +747,7 @@ def lm_prefill(params: Params, cfg: ModelConfig, tokens,
         hin = rms_norm(h, p_l["ln2"], cfg.norm_eps)
         if "moe" in p_l:
             y, _ = moe_block(p_l["moe"], cfg, hin, data_shards,
-                             n_valid=n_valid, eff_capacity=moe_cap)
+                             n_valid=n_valid)
         else:
             y = mlp_block(p_l["mlp"], cfg, hin)
         return h + y, (k, v)

@@ -66,7 +66,8 @@ def _local_dispatch(cfg: ModelConfig, x, router):
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router)
     probs = jax.nn.softmax(logits, axis=-1)
     top_w, top_ids = jax.lax.top_k(probs, k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
 
     density = jnp.mean(jax.nn.one_hot(top_ids[..., 0], e), axis=0)
     aux = jnp.sum(density * jnp.mean(probs, axis=0)) * e
@@ -89,20 +90,20 @@ def _local_dispatch(cfg: ModelConfig, x, router):
 
 
 def moe_block_ep(p: Dict[str, Any], cfg: ModelConfig,
-                 x: jnp.ndarray, n_valid=None,
-                 eff_capacity=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                 x: jnp.ndarray,
+                 n_valid=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Drop-in replacement for lm.moe_block when ep_applicable().
 
-    The capacity-stable masked dispatch (``n_valid``/``eff_capacity``,
-    serving's bucketed-MoE prefill — see ``lm.moe_dispatch``) is NOT
-    supported here: ``_local_dispatch`` computes per-shard queue
+    The masked dispatch (``n_valid``, serving's bucketed-MoE prefill —
+    see ``lm.moe_dispatch``) is NOT supported here:
+    ``_local_dispatch`` computes per-shard queue
     positions over locally contiguous token ranges, and a right-padded
     bucket would scatter real tokens across shards differently than
     the unpadded run.  ``lm.moe_block`` therefore keeps masked calls
     on the single-device path; this guard is the backstop."""
-    if n_valid is not None or eff_capacity is not None:
+    if n_valid is not None:
         raise NotImplementedError(
-            "capacity-stable masked MoE dispatch is single-device only "
+            "masked MoE dispatch is single-device only "
             "(lm.moe_block routes it off the EP path)")
     ctx = _act_ctx()
     mesh = ctx.mesh

@@ -71,13 +71,12 @@ def _tok_shapes(cfg, mode, batch, seq):
 def _dense_bundle(cfg: ModelConfig) -> ModelBundle:
     def prefill(params, batch, cache_len=None, window=None,
                 data_shards=16):
-        # n_valid/moe_cap: capacity-stable bucketed-MoE scalars the
-        # serving engine puts in the batch (traced values, see
-        # lm.moe_dispatch); absent for exact-length/non-moe prefill
+        # n_valid: the true token count of a bucketed-MoE prefill,
+        # which the serving engine puts in the batch (a traced value,
+        # see lm.moe_dispatch); absent for exact-length/non-moe prefill
         return lm.lm_prefill(params, cfg, batch["tokens"], cache_len,
                              window=window, data_shards=data_shards,
-                             n_valid=batch.get("n_valid"),
-                             moe_cap=batch.get("moe_cap"))
+                             n_valid=batch.get("n_valid"))
 
     def decode(params, cache, tokens, lengths, window=None,
                data_shards=16):

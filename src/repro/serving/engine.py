@@ -54,11 +54,11 @@ docs/PREEMPTION.md):
     length AND whose prefill math is per-position (dense/vlm): padded
     rows are positionally masked to -1e30 before softmax, so decoded
     tokens are bit-identical to the exact-length path (asserted in
-    tests/test_scheduling.py).  MoE buckets too, via capacity-stable
-    masked dispatch: expert capacity is computed from the BUCKET shape
-    while traced ``n_valid``/``moe_cap`` scalars mask routing to
-    exactly what the true length dispatches (``lm.moe_dispatch``) —
-    one compile per bucket, bit-identical expert routing.  SSM and
+    tests/test_scheduling.py).  MoE buckets too: its serving dispatch
+    is dropless (every expert's capacity is every token), and a traced
+    ``n_valid`` scalar keeps the padded positions out of routing, so
+    the bucket routes exactly what the true length routes
+    (``lm.moe_dispatch``) — one compile per bucket.  SSM and
     hybrid keep exact-length (or CHUNKED, below) prefill: their
     recurrent state integrates every input position, masked or not,
     so forcing a bucket table onto them raises
@@ -151,10 +151,10 @@ DEFAULT_TAGS = ("pallas", "reference")
 #
 # BUCKETED: decode masks the KV cache by per-slot length, so
 # right-padded (bucketed) prefill is bit-identical to exact-length
-# prefill.  "moe" qualifies via capacity-stable masked dispatch
-# (lm.moe_dispatch: capacity from the bucket SHAPE, routing masked to
-# the true length's).  NOT "ssm"/"hybrid": recurrent state integrates
-# every position, masked or not.
+# prefill.  "moe" qualifies via its masked dropless dispatch
+# (lm.moe_dispatch: every expert queues the whole bucket, and only the
+# true length's tokens route).  NOT "ssm"/"hybrid": recurrent state
+# integrates every position, masked or not.
 BUCKETED_FAMILIES = ("dense", "vlm", "moe")
 # CHUNKED: dense/vlm via the KV-offset chunk op; ssm/hybrid via the
 # recurrent-state chunk op (carried state is a traced argument).  NOT
@@ -411,8 +411,8 @@ class ServingEngine:
                     self.cfg.family, "bucketed prefill",
                     supported=BUCKETED_FAMILIES)
             self.bucket_table = prefill_buckets
-        # capacity-stable MoE bucketing: every bucketed-moe prefill
-        # batch carries traced n_valid/moe_cap scalars (lm.moe_dispatch)
+        # MoE bucketing: every bucketed-moe prefill batch carries the
+        # true token count as a traced n_valid scalar (lm.moe_dispatch)
         self._moe_masked = (self.cfg.family == "moe"
                             and self.bucket_table is not None)
         # prefill_chunk: None/False/0 = off, True = auto size (the
@@ -509,28 +509,27 @@ class ServingEngine:
             self.n_table = cache_len // self.kv_block
 
         # --- arena accounting (C3/C4): KV is interpreter-lifetime ----
+        # (the KV itself is made below, once its placement is known)
         if self.paged:
             n_blocks = (int(kv_pool_blocks) if kv_pool_blocks
                         else max_slots * self.n_table + 1)
-            self.kv_pool = self._empty_cache(n_blocks, self.kv_block)
+            kv_rows = (n_blocks, self.kv_block)
             self.pool = PagedKVPool(n_blocks, self.kv_block)
             self.block_tables = jnp.zeros((max_slots, self.n_table),
                                           jnp.int32)
             self._slot_blocks: List[List[int]] = [
                 [] for _ in range(max_slots)]
             self._slot_reserved: List[int] = [0] * max_slots
-            kv_bytes = _cache_bytes(self.kv_pool)
-            cache = None
         else:
-            cache = self._empty_cache(max_slots, cache_len)
-            kv_bytes = _cache_bytes(cache)
+            kv_rows = (max_slots, cache_len)
+        kv_shape = jax.eval_shape(lambda: self._empty_cache(*kv_rows))
+        kv_bytes = _cache_bytes(kv_shape)
         self.kv_bytes = kv_bytes
         if arena is None:
             arena = TwoStackArena(arena_bytes or align_up(
                 kv_bytes + (64 << 10)) * 2)
         self.arena = arena
         self.kv_offset = arena.allocate_persistent(kv_bytes, tag="kv_cache")
-        self.cache = cache
 
         # --- mesh sharding (tensor/expert parallel in the engine) -----
         # mesh: None = single-device (the default); a jax Mesh with a
@@ -553,19 +552,20 @@ class ServingEngine:
             c1_shape = jax.eval_shape(
                 lambda: bundle.empty_cache(1, cache_len, dtype))
             self._shard = engine_shardings(
-                self.cfg, mesh, params,
-                self.kv_pool if self.paged else self.cache,
-                global_batch=(self.pool.n_blocks if self.paged
-                              else max_slots),
-                cache1_tree=c1_shape)
+                self.cfg, mesh, params, kv_shape,
+                global_batch=kv_rows[0], cache1_tree=c1_shape)
             self.params = jax.device_put(params, self._shard["params"])
             if self.paged:
-                self.kv_pool = jax.device_put(self.kv_pool,
-                                              self._shard["cache"])
                 self.block_tables = self._pin_repl(self.block_tables)
-            else:
-                self.cache = jax.device_put(self.cache,
-                                            self._shard["cache"])
+        # the KV is made in its placement: on a mesh, each device makes
+        # only its shard (the whole ring of a large model does not fit
+        # one device beside that device's share of the weights)
+        kv = (jax.jit(lambda: self._empty_cache(*kv_rows),
+                      out_shardings=self._shard["cache"])()
+              if self._shard else self._empty_cache(*kv_rows))
+        if self.paged:
+            self.kv_pool = kv
+        self.cache = None if self.paged else kv
 
         # --- slot bookkeeping (host side, fixed size) -----------------
         self.slot_req: List[Optional[RequestResult]] = [None] * max_slots
@@ -587,12 +587,15 @@ class ServingEngine:
         self._ckpt: Dict[int, SlotCheckpoint] = {}
         # what the last step() did — the benchmark's virtual-clock cost
         # hook: prefill token counts, chunk dispatches, decode dispatch;
-        # and where its wall time went: ``readback_s`` blocked on the
-        # device's tokens, ``host_s`` the rest of the tick
-        self.last_step: Dict[str, Any] = {"prefill_tokens": [],
-                                          "chunks": 0, "decoded": False,
-                                          "processed": 0,
-                                          "readback_s": 0.0, "host_s": 0.0}
+        # where its wall time went: ``readback_s`` blocked on the
+        # device's tokens, ``host_s`` the rest of the tick; and, for
+        # moe, the expert-FFN rows its programs computed
+        # (``expert_rows``) against those routing asked for
+        # (``routed_rows``), both summed over the MoE layers
+        self._moe_layers = (
+            self.cfg.n_layers - (1 if self.cfg.first_layer_dense_ff else 0)
+            if self.cfg.n_experts else 0)
+        self.last_step: Dict[str, Any] = self._fresh_step()
 
         # --- compiled steps (init-time, like interpreter prepare) -----
         # Resolve prefill/decode through the op registry tag chain: the
@@ -652,7 +655,7 @@ class ServingEngine:
         # prefill jits once per prompt-length BUCKET when bucket_table
         # is set (BUCKETED_FAMILIES only: decode masks KV by length,
         # so padding is invisible, and moe additionally carries the
-        # capacity-stable n_valid/moe_cap scalars); exact-length
+        # traced n_valid scalar); exact-length
         # otherwise — see the BUCKETED_FAMILIES comment for why
         # ssm/hybrid are out
         self._prefill = jax.jit(functools.partial(
@@ -969,22 +972,18 @@ class ServingEngine:
                     prompt = self._padded_prompt(prompt)
                 batch = {"tokens": jnp.asarray(prompt[None])}
                 if self._moe_masked:
-                    # capacity-stable bucketed-MoE scalars: capacity is
-                    # a function of the BUCKET shape inside the trace,
-                    # while these traced values mask dispatch to exactly
-                    # what the true length m routes (lm.moe_dispatch).
+                    # bucketed MoE: the traced true length keeps the
+                    # padded positions out of routing (lm.moe_dispatch).
                     # Emitted even on the over-cap exact-length fallback
-                    # (where they degenerate to unmasked semantics) so
-                    # every prefill of a given shape shares one trace
-                    # signature.
-                    from repro.models.lm import moe_capacity
+                    # (where it masks nothing) so every prefill of a
+                    # given shape shares one trace signature.
                     batch["n_valid"] = jnp.int32(m)
-                    batch["moe_cap"] = jnp.int32(moe_capacity(self.cfg, m))
                 if req.extras:
                     for k, v in req.extras.items():
                         batch[k] = jnp.asarray(v[None])
                 _, cache1 = self._prefill((self.params, batch))
             self.last_step["prefill_tokens"].append(len(prompt))
+            self._count_moe_rows(len(prompt), m)
             self.policy.charge(req.tenant, 1.0)
         else:   # single-token prompt: slot starts from a fresh cache
             cache1 = self._empty_cache(1, self.cache_len)
@@ -1326,6 +1325,7 @@ class ServingEngine:
         which leaves what it is handed valid."""
         step = self._decode
         run = step.consume if isinstance(step, DecodeStep) else step
+        self._count_moe_rows(self.max_slots, int(self.active.sum()))
         if self.paged:
             logits, kv_pool = run(
                 (self.params, self.kv_pool, self.block_tables,
@@ -1397,13 +1397,28 @@ class ServingEngine:
         time into ``readback_s`` (blocked on the device's tokens) and
         ``host_s`` (everything else)."""
         t0 = time.perf_counter()
-        self.last_step = {"prefill_tokens": [], "chunks": 0,
-                          "decoded": False, "processed": 0,
-                          "readback_s": 0.0, "host_s": 0.0}
+        self.last_step = self._fresh_step()
         more = self._tick()
         self.last_step["host_s"] = (time.perf_counter() - t0
                                     - self.last_step["readback_s"])
         return more
+
+    @staticmethod
+    def _fresh_step() -> Dict[str, Any]:
+        return {"prefill_tokens": [], "chunks": 0, "decoded": False,
+                "processed": 0, "readback_s": 0.0, "host_s": 0.0,
+                "expert_rows": 0, "routed_rows": 0}
+
+    def _count_moe_rows(self, tokens: int, live: int) -> None:
+        """Add one program's expert-FFN rows to ``last_step``, from the
+        host's shapes and slot mask (nothing is read back): its
+        dropless dispatch queues all ``tokens`` of its shape at every
+        expert, and routing asks for ``live`` x top_k rows, in each MoE
+        layer."""
+        self.last_step["expert_rows"] += (self._moe_layers * tokens
+                                          * self.cfg.n_experts)
+        self.last_step["routed_rows"] += (self._moe_layers * live
+                                          * self.cfg.top_k)
 
     def _tick(self) -> bool:
         """The body of ``step``."""

@@ -208,3 +208,47 @@ def test_sharded_step_keeps_its_kv_buffers():
     assert out.returncode == 0, out.stderr[-4000:]
     assert out.stdout.split() == ["None", "True", "True",
                                   "16", "True", "True"]
+
+
+def test_sharded_engine_makes_its_kv_in_place():
+    """On a mesh the engine makes its KV (ring or paged pool) straight
+    into its sharding: each device makes only its shard.  A KV made
+    whole on one device first, then sharded, put deepseek-moe-16b's
+    7.5 GB ring beside chip 0's 8.2 GB of weights and ran out of
+    memory on four v5e."""
+    script = textwrap.dedent("""
+        import dataclasses, jax, numpy as np
+        from repro.configs import get_config
+        from repro.launch.mesh import make_serving_mesh
+        from repro.models import get_model
+        from repro.serving import ServingEngine
+
+        cfg = get_config("deepseek-moe-16b", reduced=True)
+        model = get_model(cfg)
+        params = model.init(jax.random.PRNGKey(0))
+        for kv_block in (None, 16):
+            made = []
+
+            def empty_cache(batch, length, dtype):
+                tree = model.empty_cache(batch, length, dtype)
+                made.append(isinstance(tree["k"], jax.core.Tracer))
+                return tree
+
+            spy = dataclasses.replace(model, empty_cache=empty_cache)
+            eng = ServingEngine(spy, params, max_slots=4, cache_len=64,
+                                kv_block=kv_block,
+                                mesh=make_serving_mesh(4))
+            kv = eng.kv_pool if eng.paged else eng.cache
+            quarter = all(s.data.nbytes * 4 == leaf.nbytes
+                          for leaf in jax.tree.leaves(kv)
+                          for s in leaf.addressable_shards)
+            print(kv_block, made == [True] * len(made), quarter)
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert out.stdout.split() == ["None", "True", "True",
+                                  "16", "True", "True"]

@@ -11,8 +11,8 @@ cycle) — and each run's decoded tokens must be IDENTICAL to
 that family's exact-length baseline:
 
   * dense/vlm: length-masked decode hides bucket/chunk padding;
-  * moe: capacity-stable masked dispatch (``lm.moe_dispatch``) makes
-    bucket padding invisible to expert routing;
+  * moe: masked dropless dispatch (``lm.moe_dispatch``) makes bucket
+    padding invisible to expert routing;
   * ssm/hybrid: the recurrent-state chunk op
     (``SERVING_PREFILL_CHUNK_STATE``) carries (conv, SSD) state across
     chunk boundaries with the padded tail an exact state no-op;

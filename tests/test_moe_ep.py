@@ -43,7 +43,7 @@ SCRIPT = textwrap.dedent("""
 
     # reference: GSPMD path, single group (no ctx -> ep not applicable)
     y_ref, aux_ref = jax.jit(
-        lambda p, x: L.moe_block(p, cfg, x, data_shards=1))(p, x)
+        lambda p, x: L.moe_block(p, cfg, x, data_shards=1, capped=True))(p, x)
 
     # EP path under the mesh ctx
     def ep(p, x):

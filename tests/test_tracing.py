@@ -212,3 +212,78 @@ def test_profiler_trace_holds_every_span(tmp_path, overlap):
     assert len(emits) < len(uids) * N_NEW
     gcs = [e for evs in lines.values() for e in evs if e[0] == "gc"]
     assert any(e[3].get("generation") == 2 for e in gcs)
+
+
+def _moe_engine(**kw):
+    cfg = get_config("deepseek-moe-16b", reduced=True)
+    m = get_model(cfg)
+    return cfg, ServingEngine(m, m.init(jax.random.PRNGKey(1)), max_slots=2,
+                              cache_len=CACHE_LEN, **kw)
+
+
+def test_moe_row_counters_follow_the_programs_shapes():
+    """``expert_rows``: every expert's dropless queue over the tokens of
+    each program's shape (the padded prefill bucket, all decode slots);
+    ``routed_rows``: the live tokens times top_k; both over the MoE
+    layers.  A dense engine's ticks count none."""
+    from repro.serving import Request
+    cfg, eng = _moe_engine()
+    moe_layers = cfg.n_layers - 1
+    prompts = _prompts(3)
+    for uid, p in enumerate(prompts):
+        eng.submit(Request(uid=uid, tokens=p, max_new_tokens=N_NEW))
+    ticks = []
+    while eng.step():
+        ticks.append(dict(eng.last_step))
+    ticks.append(dict(eng.last_step))
+    for t in ticks:
+        live = t["processed"] if t["decoded"] else 0
+        prefill = sum(t["prefill_tokens"])
+        assert t["expert_rows"] == moe_layers * cfg.n_experts * (
+            prefill + (eng.max_slots if t["decoded"] else 0))
+        assert t["routed_rows"] <= t["expert_rows"]
+    pre = sum(len(p) - 1 for p in prompts)
+    dec = sum(t["processed"] for t in ticks)
+    assert sum(t["routed_rows"] for t in ticks) == \
+        moe_layers * cfg.top_k * (pre + dec)
+    dense = _mk_engine()
+    dense.submit(Request(uid=0, tokens=prompts[0], max_new_tokens=2))
+    dense.step()
+    assert dense.last_step["expert_rows"] == dense.last_step[
+        "routed_rows"] == 0
+
+
+@pytest.mark.parametrize("overlap", [True, False])
+def test_moe_row_counters_read_nothing_back(monkeypatch, overlap):
+    """The counters are host arithmetic: a moe engine's tick reads the
+    device back only where it reads a step's tokens (every way numpy
+    or Python can read a device array is counted)."""
+    from jax._src.array import ArrayImpl
+    from repro.serving import Request
+    _, eng = _moe_engine(overlap=overlap)
+    for uid, p in enumerate(_prompts(3)):
+        eng.submit(Request(uid=uid, tokens=p, max_new_tokens=N_NEW))
+    eng.step()                        # compile the first programs
+    reads = []
+
+    def counted(name):
+        inner = getattr(ArrayImpl, name)
+
+        def read(self, *a, **kw):
+            reads.append(name)
+            return inner(self, *a, **kw)
+        monkeypatch.setattr(ArrayImpl, name, read)
+
+    for name in ("__buffer__", "__array__", "__int__", "__index__",
+                 "__float__", "__bool__", "item", "tolist"):
+        counted(name)
+    more = True
+    while more:
+        reads.clear()
+        more = eng.step()
+        last = eng.last_step
+        read_back = last["readback_s"] > 0
+        assert last["expert_rows"] > 0 or not (last["decoded"]
+                                               or last["prefill_tokens"])
+        # sync mode reads the logits, lengths and tokens of its step
+        assert len(reads) == (0 if not read_back else 1 if overlap else 3)

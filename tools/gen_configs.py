@@ -66,13 +66,16 @@ ARCHS = {
                     vocab=102400, n_experts=64, top_k=6,
                     n_shared_experts=2, moe_d_ff=1408,
                     first_layer_dense_ff=10944, act="silu",
-                    sliding_window=8192, source="arXiv:2401.06066"),
+                    norm_topk_prob=False,
+                    source="https://huggingface.co/deepseek-ai/"
+                           "deepseek-moe-16b-base/blob/main/config.json"),
         REDUCED=dict(arch_id="deepseek-moe-16b-smoke", family="moe",
                      n_layers=2, d_model=256, n_heads=4, n_kv_heads=4,
                      d_ff=0, vocab=512, n_experts=4, top_k=2,
                      n_shared_experts=1, moe_d_ff=128,
                      first_layer_dense_ff=512, act="silu",
-                     capacity_factor=8.0, dtype="float32", source="arXiv:2401.06066")),
+                     capacity_factor=8.0, norm_topk_prob=False,
+                     dtype="float32", source="arXiv:2401.06066")),
     "yi_6b": dict(
         title="Yi-6B [dense] — llama-arch GQA", cite="[arXiv:2403.04652]",
         CONFIG=dict(arch_id="yi-6b", family="dense", n_layers=32,
@@ -90,7 +93,8 @@ ARCHS = {
                     d_model=2048, n_heads=32, n_kv_heads=4, d_ff=0,
                     vocab=151936, head_dim=128, qk_norm=True,
                     n_experts=128, top_k=8, moe_d_ff=768, act="silu",
-                    rope_base=1000000.0, sliding_window=8192,
+                    norm_topk_prob=True, rope_base=1000000.0,
+                    sliding_window=8192,
                     source="hf:Qwen/Qwen3-30B-A3B"),
         REDUCED=dict(arch_id="qwen3-moe-30b-a3b-smoke", family="moe",
                      n_layers=2, d_model=256, n_heads=4, n_kv_heads=2,
